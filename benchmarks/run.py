@@ -67,6 +67,8 @@ from benchmarks import (  # noqa: E402
     table4_latency,
 )
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
@@ -119,6 +121,7 @@ def main(argv=None) -> int:
                     help="where to write BENCH_*.json")
     args = ap.parse_args(argv)
     translation = not args.no_translation_cache
+    enable_compile_cache()
 
     if args.transforms:
         csv_rows: list = []
@@ -140,11 +143,11 @@ def main(argv=None) -> int:
         if len(jax.devices()) < args.mesh:
             # The pre-import peek reads sys.argv; a programmatic
             # main(argv=...) call (or an already-initialized backend)
-            # cannot grow the device count retroactively — say so rather
-            # than silently running unplaced.
-            print(f"warning: --mesh {args.mesh} requested but only "
-                  f"{len(jax.devices())} devices are visible; shards run "
-                  "unplaced (metrics are unaffected)", file=sys.stderr)
+            # cannot grow the device count retroactively.
+            print(f"error: --mesh {args.mesh} requested but only "
+                  f"{len(jax.devices())} devices are visible",
+                  file=sys.stderr)
+            return 2
 
     csv_rows: list = []
     fig4_utilization.run(csv_rows)

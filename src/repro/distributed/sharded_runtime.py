@@ -185,14 +185,13 @@ class ShardedDMARuntime:
         if num_shards is None:
             num_shards = mesh_shards
         if mesh is not None and num_shards != mesh_shards:
-            if explicit_mesh:
+            if explicit_mesh or num_shards > 1:
                 raise ValueError(
                     f"num_shards={num_shards} but the mesh has "
                     f"{mesh_shards} devices; drop one or make them agree")
-            # An *ambient* mesh of the wrong size must not veto an
-            # explicit shard count (e.g. the mesh-1 perf cell running
-            # inside someone else's 8-device context): shards are
-            # logical, so just run unplaced — no metric depends on it.
+            # A single shard has nothing to place: it runs on the default
+            # device inside any ambient mesh (e.g. the mesh-1 perf cell
+            # inside someone else's 8-device context).
             mesh = None
         if num_shards < 1:
             raise ValueError("need >= 1 shard")

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .descriptor_copy import row_view
+
 
 def _gather_kernel(idx_ref, tok_ref, out_ref):
     i = pl.program_id(0)
@@ -31,15 +33,17 @@ def moe_gather(token_idx: jax.Array, tokens: jax.Array, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, d), lambda i, idx: (jnp.maximum(idx[i], 0), 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx: (i, 0)),
+        in_specs=[pl.BlockSpec((None, 1, d),
+                               lambda i, idx: (jnp.maximum(idx[i], 0), 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, d), lambda i, idx: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), tokens.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), tokens.dtype),
         interpret=interpret,
-    )(token_idx.astype(jnp.int32), tokens)
+    )(token_idx.astype(jnp.int32), row_view(tokens))
+    return out.reshape(n, d)
 
 
 def _combine_kernel(slot_ref, w_ref, *refs):
@@ -67,18 +71,19 @@ def moe_combine(inv_slot: jax.Array, inv_weight: jax.Array,
     d = expert_out.shape[1]
 
     def make_map(j):
-        return lambda i, slot, w: (jnp.maximum(slot[i, j], 0), 0)
+        return lambda i, slot, w: (jnp.maximum(slot[i, j], 0), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(t,),
-        in_specs=[pl.BlockSpec((1, d), make_map(j)) for j in range(k)],
-        out_specs=pl.BlockSpec((1, d), lambda i, slot, w: (i, 0)),
+        in_specs=[pl.BlockSpec((None, 1, d), make_map(j)) for j in range(k)],
+        out_specs=pl.BlockSpec((None, 1, d), lambda i, slot, w: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _combine_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, d), expert_out.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, d), expert_out.dtype),
         interpret=interpret,
     )(inv_slot.astype(jnp.int32), inv_weight.astype(jnp.float32),
-      *([expert_out] * k))
+      *([row_view(expert_out)] * k))
+    return out.reshape(t, d)

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .descriptor_copy import from_row_view, row_view
+
 
 def _pipeline_kernel(src_idx_ref, dst_idx_ref, src_hbm, dst_in, dst_hbm,
                      scratch, in_sems, out_sems, *, depth: int):
@@ -62,31 +64,37 @@ def prefetched_chain_copy(src_idx: jax.Array, dst_idx: jax.Array,
                           src: jax.Array, dst: jax.Array, *,
                           depth: int = 2, interpret: bool = False):
     """Row-pool copy with an explicit `depth`-deep descriptor prefetch
-    pipeline. Semantics match `descriptor_copy` for non-negative indices."""
+    pipeline. Semantics match `descriptor_copy` for non-negative indices.
+
+    Pools and bounce buffers are row views (:func:`row_view`), so each
+    DMA moves one whole leading-axis row.
+    """
     n = src_idx.shape[0]
-    rows, unit = src.shape
+    src3 = row_view(src, packed=True)
+    dst3 = row_view(dst, packed=True)
     depth = max(2, min(depth, max(n, 2)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((depth, unit), src.dtype),
+            pltpu.VMEM((depth,) + src3.shape[1:], src3.dtype),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
         ],
     )
     kernel = functools.partial(_pipeline_kernel, depth=depth)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
+        out_shape=jax.ShapeDtypeStruct(dst3.shape, dst3.dtype),
         input_output_aliases={3: 0},
         interpret=interpret,
     )(jnp.maximum(src_idx.astype(jnp.int32), 0),
-      jnp.maximum(dst_idx.astype(jnp.int32), 0), src, dst)
+      jnp.maximum(dst_idx.astype(jnp.int32), 0), src3, dst3)
+    return from_row_view(out, dst)

@@ -20,32 +20,26 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.optim.compress import BLOCK
 
+from .descriptor_copy import row_move_call
 
-def _quantize_copy_kernel(src_idx_ref, dst_idx_ref, src_ref, dst_in_ref,
-                          dst_ref):
-    """Body: round-trip one row through per-BLOCK int8 scales, then write.
 
-    Inactive descriptors (-1) write nothing; dst_in_ref is the aliased
-    destination pool (untouched rows keep their contents).
+def _quantize_row(src_ref, dst_ref):
+    """Round-trip one row through per-BLOCK int8 scales, then write it.
+
+    Each 256-lane block is a static lane slice of the row. The int8 cast
+    is left out: the clipped, rounded quotients are integers in
+    [-127, 127], which float32 holds exactly, so the values are those of
+    the int8 round trip.
     """
-    del dst_in_ref
-    i = pl.program_id(0)
-    active = (src_idx_ref[i] >= 0) & (dst_idx_ref[i] >= 0)
-
-    @pl.when(active)
-    def _():
-        row = src_ref[...].astype(jnp.float32)
-        blocks = row.reshape(-1, BLOCK)
-        scale = jnp.maximum(
-            jnp.max(jnp.abs(blocks), axis=1, keepdims=True) / 127.0, 1e-12)
-        q = jnp.clip(jnp.round(blocks / scale), -127, 127).astype(jnp.int8)
-        deq = (q.astype(jnp.float32) * scale).reshape(row.shape)
-        dst_ref[...] = deq.reshape(src_ref.shape).astype(dst_ref.dtype)
+    for b in range(src_ref.shape[-1] // BLOCK):
+        lanes = slice(b * BLOCK, (b + 1) * BLOCK)
+        blk = src_ref[:, lanes].astype(jnp.float32)
+        scale = jnp.maximum(jnp.max(jnp.abs(blk)) / 127.0, 1e-12)
+        q = jnp.clip(jnp.round(blk / scale), -127, 127)
+        dst_ref[:, lanes] = (q * scale).astype(dst_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -56,29 +50,11 @@ def quantize_copy(src_idx: jax.Array, dst_idx: jax.Array, src: jax.Array,
     src/dst: (rows, unit) row pools with ``unit % BLOCK == 0`` (each row
     is a whole number of quantization blocks).
     """
-    n = src_idx.shape[0]
     unit = src.shape[1]
     if unit % BLOCK:
         raise ValueError(f"row width {unit} is not a multiple of {BLOCK}")
-
-    dst_map = lambda i, sidx, didx: (jnp.maximum(didx[i], 0), 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, unit),
-                         lambda i, sidx, didx: (jnp.maximum(sidx[i], 0), 0)),
-            pl.BlockSpec((1, unit), dst_map),
-        ],
-        out_specs=pl.BlockSpec((1, unit), dst_map),
-    )
-    return pl.pallas_call(
-        _quantize_copy_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
-        input_output_aliases={3: 0},   # dst pool (after 2 scalars + src)
-        interpret=interpret,
-    )(src_idx.astype(jnp.int32), dst_idx.astype(jnp.int32), src, dst)
+    return row_move_call(_quantize_row, src_idx, dst_idx, src, dst,
+                         interpret=interpret)
 
 
 def quantize_copy_bucketed(src_idx: jax.Array, dst_idx: jax.Array,

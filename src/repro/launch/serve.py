@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.runtime import SubmitRequest
 from repro.serve import Request, ServeEngine
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     params = init_params(jax.random.PRNGKey(args.seed), cfg)
     engine = ServeEngine(params, cfg, capacity=args.capacity,

@@ -19,6 +19,8 @@ from repro.configs import get_config
 from repro.data import DataConfig
 from repro.distributed import shardlib
 from repro.distributed.sharding import activation_rules
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.train import Trainer, TrainConfig, TrainerConfig
 
 
@@ -47,14 +49,15 @@ def main():
     if args.distributed_init:
         jax.distributed.initialize()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     if args.mesh_data:
         if args.multi_pod:
-            mesh = jax.make_mesh((2, args.mesh_data, args.mesh_model),
-                                 ("pod", "data", "model"))
+            mesh = make_mesh((2, args.mesh_data, args.mesh_model),
+                             ("pod", "data", "model"))
         else:
-            mesh = jax.make_mesh((args.mesh_data, args.mesh_model),
-                                 ("data", "model"))
+            mesh = make_mesh((args.mesh_data, args.mesh_model),
+                             ("data", "model"))
         shardlib.set_mesh(mesh)
         shardlib.set_rules(activation_rules(mesh))
 

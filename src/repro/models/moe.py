@@ -134,7 +134,6 @@ def _moe_ffn_ep(params, x: jax.Array, cfg: ModelConfig, act_fn: str, mesh):
     the model axis. Dispatch itself moves zero bytes across chips — the
     descriptor plan stays local, exactly the paper's cheap-descriptor thesis.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed import shardlib
 
@@ -198,11 +197,11 @@ def _moe_ffn_ep(params, x: jax.Array, cfg: ModelConfig, act_fn: str, mesh):
     t_spec = P(batch_ax, None)
     w_spec = P("model", None, None)
     other_axes = tuple(a for a in mesh.axis_names)
-    y, aux, dropped = shard_map(
+    y, aux, dropped = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(t_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=(t_spec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x.reshape(b * s, d), params["router"],
       params["w_gate"], params["w_up"], params["w_down"])
 

@@ -114,14 +114,20 @@ DEFAULT_SHARDED_SPEC = ShardedCellSpec()
 
 
 def _mesh_for(num_shards: int):
-    """A real 1-D device mesh when the host has enough devices, else None
-    (logical shards — metrics are placement-independent either way)."""
+    """The mesh that places a cell's shards, one per device.
+
+    A single-device process runs the shards logically (no mesh): the
+    cell's metrics do not depend on placement. A multi-device process
+    places them, and then needs a device for every shard.
+    """
     import jax
     devices = jax.devices()
-    if num_shards > 1 and len(devices) >= num_shards:
-        return jax.sharding.Mesh(
-            np.asarray(devices[:num_shards]), ("dma",))
-    return None
+    if num_shards == 1 or len(devices) == 1:
+        return None
+    if len(devices) < num_shards:
+        raise RuntimeError(
+            f"cannot place {num_shards} shards on {len(devices)} devices")
+    return jax.sharding.Mesh(np.asarray(devices[:num_shards]), ("dma",))
 
 
 def _make_runtime(mesh: int, spec: ShardedCellSpec):

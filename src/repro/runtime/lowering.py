@@ -189,8 +189,7 @@ class LoweredChain:
     # -- row-pool artifact (fused blocked_2d drain) --------------------------
     def _call_rows2d(self, d: DescriptorArray, src: jax.Array,
                      dst: jax.Array) -> Optional[jax.Array]:
-        from repro.kernels.descriptor_copy import descriptor_copy_bucketed
-        from repro.kernels.ops import _interpret
+        from repro.kernels.ops import descriptor_copy_bucketed_op
 
         if self.sig.transform:
             return None   # fused 2-D batches are identity-only
@@ -203,9 +202,9 @@ class LoweredChain:
         sidx = np.where(active, np.asarray(d.src, np.int32), -1)
         didx = np.where(active, np.asarray(d.dst, np.int32), -1)
         self.dispatches += 1
-        out = descriptor_copy_bucketed(
+        out = descriptor_copy_bucketed_op(
             jnp.asarray(sidx), jnp.asarray(didx), src2, dst2,
-            n_bucket=self.sig.n_class, interpret=_interpret())
+            n_bucket=self.sig.n_class)
         return out.reshape(shape)
 
     # -- linear-pool artifacts (serial tier) ---------------------------------
@@ -234,8 +233,11 @@ class LoweredChain:
                 and token in ("", "kv8")
                 and src.shape[0] % unit == 0 and dst.shape[0] % unit == 0
                 and not np.any(so % unit) and not np.any(do % unit)):
-            from repro.kernels.descriptor_copy import descriptor_copy_bucketed
-            from repro.kernels.ops import _interpret
+            from repro.kernels.ops import (
+                _interpret,
+                descriptor_copy_bucketed_op,
+                quantize_copy_bucketed_op,
+            )
             # The kv8 Pallas route needs row-local 256-blocks to equal the
             # pool-absolute blocks of the transform contract: offsets are
             # unit-multiples and the pool is a unit-multiple long, so
@@ -248,19 +250,10 @@ class LoweredChain:
                 sidx = jnp.asarray(np.where(ln == unit, so // unit, -1))
                 didx = jnp.asarray(np.where(ln == unit, do // unit, -1))
                 self.dispatches += 1
-                if token == "kv8":
-                    from repro.kernels.quantize_copy import (
-                        quantize_copy_bucketed,
-                    )
-                    out = quantize_copy_bucketed(
-                        sidx, didx, src.reshape(-1, unit),
-                        dst.reshape(-1, unit),
-                        n_bucket=self.sig.n_class, interpret=False)
-                else:
-                    out = descriptor_copy_bucketed(
-                        sidx, didx, src.reshape(-1, unit),
-                        dst.reshape(-1, unit),
-                        n_bucket=self.sig.n_class, interpret=False)
+                op = (quantize_copy_bucketed_op if token == "kv8"
+                      else descriptor_copy_bucketed_op)
+                out = op(sidx, didx, src.reshape(-1, unit),
+                         dst.reshape(-1, unit), n_bucket=self.sig.n_class)
                 return out.reshape(dst.shape)
         fn = _EXEC.get((self.mode, token))
         if fn is None:

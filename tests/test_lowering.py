@@ -524,3 +524,64 @@ if _HAVE_HYPOTHESIS:
         want_d, want_stats = coalesce(d, max_len=max_len)
         _chains_equal(res.planned, want_d)
         assert res.stats == want_stats
+
+
+# ---------------------------------------------------------------------------
+# Kernel entry points: backend choice, launch counts, inactive descriptors
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True),
+                                               ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    import jax
+
+    from repro.kernels.ops import _interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            _interpret()
+    else:
+        assert _interpret() is interpret
+
+
+def test_kernel_entry_points_report_launches():
+    from jax import monitoring
+
+    from repro.kernels import ref
+    from repro.kernels.ops import LAUNCH_EVENT, descriptor_copy_bucketed_op
+    rng = np.random.default_rng(7)
+    src = jnp.asarray(rng.standard_normal((16, 128)), jnp.float32)
+    dst = jnp.zeros((16, 128), jnp.float32)
+    sidx = jnp.asarray([4, 9, 2], jnp.int32)
+    didx = jnp.asarray([0, 7, 15], jnp.int32)
+    seen = []
+
+    def listener(event, **kw):
+        if event == LAUNCH_EVENT:
+            seen.append(kw["kernel"])
+
+    monitoring.register_event_listener(listener)
+    try:
+        out = descriptor_copy_bucketed_op(sidx, didx, src, dst, n_bucket=4)
+    finally:
+        monitoring.unregister_event_listener(listener)
+    assert seen == ["descriptor_copy_bucketed"]
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(ref.descriptor_copy_ref(sidx, didx,
+                                                            src, dst)))
+
+
+def test_inactive_descriptors_repeat_an_active_move():
+    from repro.kernels.descriptor_copy import descriptor_copy, fill_inactive
+    s, d, any_active = fill_inactive(jnp.asarray([-1, 2, -1, 0, 3]),
+                                     jnp.asarray([5, 1, 3, 4, -1]))
+    assert bool(any_active)
+    np.testing.assert_array_equal(np.asarray(s), [2, 2, 2, 0, 0])
+    np.testing.assert_array_equal(np.asarray(d), [1, 1, 1, 4, 4])
+    # No active descriptor at all: the pool comes back unchanged.
+    src = jnp.ones((4, 128), jnp.float32)
+    dst = jnp.arange(4 * 128, dtype=jnp.float32).reshape(4, 128)
+    out = descriptor_copy(jnp.full((3,), -1, jnp.int32),
+                          jnp.asarray([0, 1, 2], jnp.int32), src, dst,
+                          interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(dst))

@@ -565,3 +565,21 @@ def test_sharded_serve_without_kv_pool_routes_round_robin():
               for u in range(4)]
     assert shards == [0, 1, 0, 1]
     assert eng.remote_page_reads == 0
+
+
+def test_ambient_mesh_of_wrong_size_rejects_multi_shard_count():
+    # More than one shard under a mesh of another size would run unplaced
+    # (all shards on the default device): refuse instead.
+    with shardlib.use_mesh(_FakeMesh()):   # 2x2 = 4 ambient shards
+        with pytest.raises(ValueError, match="mesh has 4"):
+            ShardedDMARuntime(num_shards=2)
+
+
+def test_sharded_cell_refuses_to_place_more_shards_than_devices(monkeypatch):
+    from repro.perf import sharded_cell
+    monkeypatch.setattr(jax, "devices", lambda: [object(), object()])
+    with pytest.raises(RuntimeError, match="4 shards on 2 devices"):
+        sharded_cell._mesh_for(4)
+    # one visible device: shards stay logical, no mesh is asked for
+    monkeypatch.setattr(jax, "devices", lambda: [object()])
+    assert sharded_cell._mesh_for(4) is None

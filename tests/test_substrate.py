@@ -119,7 +119,6 @@ def test_compression_ratio_near_4x():
 def test_compressed_psum_under_shard_map():
     """Compressed allreduce over a 'pod' axis == mean of shards (approx)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     devs = np.array(jax.devices()[:1])
     mesh = Mesh(devs.reshape(1), ("pod",))
     g = {"w": jnp.arange(8, dtype=jnp.float32) / 7.0}
@@ -128,8 +127,8 @@ def test_compressed_psum_under_shard_map():
     def fn(g, r):
         return optim.compressed_psum_tree(g, r, "pod")
 
-    out, new_r = shard_map(fn, mesh=mesh,
-                           in_specs=(P(), P()), out_specs=(P(), P()))(g, r)
+    out, new_r = jax.shard_map(fn, mesh=mesh,
+                               in_specs=(P(), P()), out_specs=(P(), P()))(g, r)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=0.02)
 
@@ -369,3 +368,51 @@ def test_serve_engine_slot_reuse_is_clean():
                                               max_new_tokens=3)))
     out_fresh = eng2.run(max_steps=100)[2].output
     assert out_reused == out_fresh
+
+
+# ---------------------------------------------------------------------------
+# Launch helpers: compile cache placement, mesh axis types
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_uses_env_dir_and_sets_nothing(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+
+
+def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch):
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]
+
+
+def test_launch_meshes_have_auto_axes():
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert tuple(mesh.axis_types) == (AxisType.Auto, AxisType.Auto)
+
+
+def test_chip_entry_points_do_not_import_dryrun():
+    # launch/dryrun.py sets XLA_FLAGS for 512 host devices when imported;
+    # a process that drives a chip must never load it.
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys, chip_smoke, repro.launch.serve, repro.launch.train,"
+            " benchmarks.run; print('repro.launch.dryrun' in sys.modules)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([os.path.join(repo, "src"), repo]))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split()[-1] == "False"
