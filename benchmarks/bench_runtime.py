@@ -106,9 +106,8 @@ def _bench_tracing(n_desc: int = 256, rounds: int = 5, seed: int = 0) -> dict:
     The observability contract is off-by-default-cheap: every hook site is
     one attribute test when no tracer is attached, and one sampling hash
     when one is attached at rate 0. ``tracing_off_overhead_ratio`` is the
-    metric the overhead guard test bounds (<= 2%) and the wall-clock trend
-    lane watches; rounds interleave the three variants so machine noise
-    hits them equally.
+    metric the overhead guard test bounds (<= 2%); rounds interleave the
+    three variants so machine noise hits them equally.
     """
     from repro.obs.trace import Tracer
 

@@ -9,14 +9,12 @@ median). The gated copies live in BENCH_perf.json; here they are
 *reported*, with the wall-clock migration drain time isolated under
 ``wall_clock``, which never enters the deterministic section.
 
-The ``wall_clock`` section also carries the trend series
-(benchmarks/trend.py): ``resize_mesh4_seconds`` and
-``migration_overlap_ratio_mesh4`` (PR 9 async fabric), plus the two
-virtual-addressing series — ``tlb_hit_rate_L13``, the DDR3 MMU cell's
+The ``wall_clock`` section also reports ``resize_mesh4_seconds`` and
+``migration_overlap_ratio_mesh4`` (the async fabric), plus two
+virtual-addressing figures — ``tlb_hit_rate_L13``, the DDR3 MMU cell's
 IOTLB hit rate under chain-lookahead prefetch, and
 ``first_touch_latency_rounds_mesh4``, the fabric rounds from touching an
-ownership-flipped page to residency. All three echoed metrics are
-deterministic, so sustained drift is a real regression, not noise.
+ownership-flipped page to residency.
 
 The defrag A/B times remap-based compaction (a page-table update)
 against the legacy copy leg through the DMA runtime on the *same*

@@ -179,6 +179,11 @@ class DescriptorArray:
         return jnp.all(self.done == 1)
 
 
+#: Conversions of a field to NumPy that :func:`to_packed` asks for (one
+#: per call; ``done`` is read twice): the runtime's ``d2h_reads`` counter.
+TO_PACKED_READS = 7
+
+
 def to_packed(
     d: DescriptorArray,
     *,

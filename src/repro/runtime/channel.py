@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.core.descriptor import (
     CONFIG_IRQ_ENABLE,
+    TO_PACKED_READS,
     DescriptorArray,
     to_packed,
 )
@@ -47,7 +48,7 @@ from repro.core.transform import (
     transform_source_view,
 )
 
-from repro.obs.trace import Tracer, monotonic
+from repro.obs.trace import NO_SPAN, Tracer, monotonic
 
 from .completion import CompletionQueue
 from .instrumentation import PerfProbe
@@ -182,29 +183,35 @@ class Channel:
         n = d.num_descriptors
         if n != len(tickets):
             raise ValueError("one ticket per descriptor")
-        packed = to_packed(d)
-        irq = (np.asarray(d.config) & int(CONFIG_IRQ_ENABLE)) != 0
-        try:
-            slots = self.ring.push_table(packed, tickets, irq=irq)
-        except RingFull:
-            self.stats.ring_full_events += 1
+        tr = self.tracer
+        with (NO_SPAN if tr is None else
+              tr.span("ring.push", self.track, ring=False)):
+            with (NO_SPAN if tr is None else
+                  tr.span("ring.pack", self.track, ring=False)):
+                packed = to_packed(d)
+                irq = (np.asarray(d.config) & int(CONFIG_IRQ_ENABLE)) != 0
+            if tr is not None:
+                tr.count("d2h_reads", TO_PACKED_READS + 1)
+            try:
+                slots = self.ring.push_table(packed, tickets, irq=irq)
+            except RingFull:
+                self.stats.ring_full_events += 1
+                if self.probe is not None:
+                    self.probe.on_ring_full(self.name)
+                if tr is not None and tickets and tr.sampled(tickets[0]):
+                    tr.instant("ring_full", self.track,
+                               ticket=int(tickets[0]), n=n)
+                raise
+            self.stats.submitted += n
+            occupancy = self.ring.capacity - self.ring.free_slots
+            if occupancy > self.stats.occupancy_peak:
+                self.stats.occupancy_peak = occupancy
             if self.probe is not None:
-                self.probe.on_ring_full(self.name)
-            tr = self.tracer
-            if tr is not None and tickets and tr.sampled(tickets[0]):
-                tr.instant("ring_full", self.track, ticket=int(tickets[0]),
-                           n=n)
-            raise
-        self.stats.submitted += n
-        occupancy = self.ring.capacity - self.ring.free_slots
-        if occupancy > self.stats.occupancy_peak:
-            self.stats.occupancy_peak = occupancy
-        if self.probe is not None:
-            self.probe.on_occupancy(self.name, occupancy)
-        if self.cfg.tier != "control":
-            self.pending.append(_Batch(list(map(int, tickets)), slots, d,
-                                       src_pool, dst_pool, lowered,
-                                       transform))
+                self.probe.on_occupancy(self.name, occupancy)
+            if self.cfg.tier != "control":
+                self.pending.append(_Batch(list(map(int, tickets)), slots, d,
+                                           src_pool, dst_pool, lowered,
+                                           transform))
         return slots
 
     # -- execution ----------------------------------------------------------
@@ -215,24 +222,33 @@ class Channel:
     def _execute(self, d: DescriptorArray, src: jax.Array,
                  dst: jax.Array) -> jax.Array:
         tier = self.cfg.tier
-        if tier == "serial":
-            out, _ = execute_serial(d, src, dst, max_len=self.cfg.max_len)
-        elif tier == "blocked":
-            out, _ = execute_blocked(d, src, dst, unit=self.cfg.unit)
-        elif tier == "blocked_2d":
-            if self.cfg.use_kernel:
-                from repro.kernels import descriptor_copy_op
+        tr = self.tracer
+        if tier == "blocked_2d" and self.cfg.use_kernel:
+            from repro.kernels import descriptor_copy_op
+            with (NO_SPAN if tr is None else
+                  tr.span("drain.pull", self.track, ring=False)):
+                active = np.asarray(d.length) >= 0
+            if tr is not None:
+                tr.count("d2h_reads")
+            with (NO_SPAN if tr is None else
+                  tr.span("drain.enqueue", self.track, ring=False)):
                 shape = dst.shape
                 src2 = src.reshape(src.shape[0], -1)
                 dst2 = dst.reshape(dst.shape[0], -1)
-                active = np.asarray(d.length) >= 0
                 sidx = jnp.where(jnp.asarray(active), d.src, -1)
                 didx = jnp.where(jnp.asarray(active), d.dst, -1)
-                out = descriptor_copy_op(sidx, didx, src2, dst2).reshape(shape)
+                return descriptor_copy_op(sidx, didx, src2,
+                                          dst2).reshape(shape)
+        if tier not in ("serial", "blocked", "blocked_2d"):
+            raise ValueError(f"tier {tier!r} carries no data")
+        with (NO_SPAN if tr is None else
+              tr.span("drain.enqueue", self.track, ring=False)):
+            if tier == "serial":
+                out, _ = execute_serial(d, src, dst, max_len=self.cfg.max_len)
+            elif tier == "blocked":
+                out, _ = execute_blocked(d, src, dst, unit=self.cfg.unit)
             else:
                 out, _ = execute_blocked_2d(d, src, dst)
-        else:
-            raise ValueError(f"tier {tier!r} carries no data")
         return out
 
     def _execute_transformed(self, t: Optional[TransformSpec],
@@ -265,38 +281,43 @@ class Channel:
         b = self.pending.popleft()
         src = pools[b.src_pool]
         dst = pools[b.dst_pool]
-        t0 = monotonic()
-        out = None
-        if b.lowered is not None:
-            # Translation-cache fast path: a compiled artifact for this
-            # chain's signature (transform token included, so a fused
-            # artifact applies the transform). It declines (None) whenever
-            # substituting for the legacy engine could change a single bit.
-            out = b.lowered(b.descs, src, dst, max_len=self.cfg.max_len)
-        if out is None:
-            out = self._execute_transformed(b.transform, b.descs, src, dst)
-        pools[b.dst_pool] = out
-        dt = monotonic() - t0
-        for slot in b.slots:
-            self.ring.mark_done(slot)
-        self.stats.drained += b.descs.num_descriptors
-        self.stats.batches += 1
-        self.stats.drain_seconds += dt
-        if self.probe is not None:
-            self.probe.on_drain(self.name,
-                                n_descriptors=b.descs.num_descriptors,
-                                seconds=dt)
         tr = self.tracer
-        if tr is not None and b.tickets and tr.sampled(b.tickets[0]):
-            tr.complete("drain", self.track, t0 * 1e6, dt * 1e6,
-                        ticket=b.tickets[0],
-                        n=b.descs.num_descriptors,
-                        lowered=b.lowered is not None)
-            # every slot of the batch just received its §II-D all-ones
-            # writeback (mark_done above) — one instant marks the batch
-            tr.instant("writeback", self.track, ticket=b.tickets[0],
-                       n_slots=len(b.slots))
-        self._retire()
+        rec = tr is not None and bool(b.tickets) and tr.sampled(b.tickets[0])
+        with (NO_SPAN if tr is None else
+              tr.span("drain", self.track, ring=rec,
+                      ticket=b.tickets[0] if b.tickets else None,
+                      n=b.descs.num_descriptors,
+                      lowered=b.lowered is not None)):
+            t0 = monotonic()
+            out = None
+            if b.lowered is not None:
+                # Translation-cache fast path: a compiled artifact for this
+                # chain's signature (transform token included, so a fused
+                # artifact applies the transform). It declines (None)
+                # whenever substituting for the legacy engine could change
+                # a single bit.
+                out = b.lowered(b.descs, src, dst, max_len=self.cfg.max_len,
+                                tracer=tr)
+            if out is None:
+                out = self._execute_transformed(b.transform, b.descs, src,
+                                                dst)
+            pools[b.dst_pool] = out
+            dt = monotonic() - t0
+            for slot in b.slots:
+                self.ring.mark_done(slot)
+            self.stats.drained += b.descs.num_descriptors
+            self.stats.batches += 1
+            self.stats.drain_seconds += dt
+            if self.probe is not None:
+                self.probe.on_drain(self.name,
+                                    n_descriptors=b.descs.num_descriptors,
+                                    seconds=dt)
+            if rec:
+                # every slot of the batch just received its §II-D all-ones
+                # writeback (mark_done above) — one instant marks the batch
+                tr.instant("writeback", self.track, ticket=b.tickets[0],
+                           n_slots=len(b.slots))
+            self._retire()
         return True
 
     def _retire(self) -> bool:

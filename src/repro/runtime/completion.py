@@ -18,7 +18,7 @@ import dataclasses
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional
 
-from repro.obs.trace import Tracer, monotonic
+from repro.obs.trace import NO_SPAN, Tracer
 
 from .ring import RingEntry
 
@@ -76,17 +76,19 @@ class CompletionQueue:
     def poll(self, max_events: Optional[int] = None) -> List[CompletionRecord]:
         """Drain up to ``max_events`` records, firing callbacks in order."""
         tr = self.tracer
-        t0 = monotonic() if tr is not None else 0.0
         out: List[CompletionRecord] = []
-        while self._events and (max_events is None or len(out) < max_events):
-            rec = self._events.popleft()
-            cb = self._callbacks.pop(rec.ticket, None)
-            if cb is not None:
-                cb(rec)
-            out.append(rec)
-            self.delivered += 1
-        if out and tr is not None and tr.sampled(out[0].ticket):
-            tr.complete("completion.poll", self.track, t0 * 1e6,
-                        (monotonic() - t0) * 1e6,
-                        n_events=len(out), first_ticket=int(out[0].ticket))
+        with (NO_SPAN if tr is None else
+              tr.span("completion.poll", self.track, ring=False)) as sp:
+            while self._events and (max_events is None
+                                    or len(out) < max_events):
+                rec = self._events.popleft()
+                cb = self._callbacks.pop(rec.ticket, None)
+                if cb is not None:
+                    cb(rec)
+                out.append(rec)
+                self.delivered += 1
+            if out and tr is not None and tr.sampled(out[0].ticket):
+                sp.ring = True
+                sp.args.update(n_events=len(out),
+                               first_ticket=int(out[0].ticket))
         return out

@@ -38,10 +38,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
-from repro.obs.trace import monotonic
+from repro.obs.trace import NO_SPAN
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,7 @@ from repro.core.descriptor import (
 )
 from repro.core.prefetch import estimate_hit_rate
 from repro.core.signature import (
+    CANONICALIZE_READS,
     CanonicalChain,
     ChainSignature,
     canonicalize,
@@ -170,10 +172,12 @@ def _pad_block(so: np.ndarray, do: np.ndarray, ln: np.ndarray,
 class LoweredChain:
     """The compiled artifact for one signature bucket.
 
-    Callable as ``lowered(descs, src, dst, max_len=...) -> dst' | None``;
-    ``None`` means "not safe to substitute for the legacy engine here —
-    run the legacy path". ``dispatches`` counts successful substitutions
-    (one artifact, many dispatches, is the whole point).
+    Callable as ``lowered(descs, src, dst, max_len=..., tracer=...) ->
+    dst' | None``; ``None`` means "not safe to substitute for the legacy
+    engine here — run the legacy path". ``dispatches`` counts successful
+    substitutions (one artifact, many dispatches, is the whole point). A
+    ``tracer`` times the descriptor reads back to the host (``drain.pull``)
+    apart from the dispatch of the copy program (``drain.enqueue``).
     """
 
     def __init__(self, sig: ChainSignature):
@@ -188,37 +192,46 @@ class LoweredChain:
 
     # -- row-pool artifact (fused blocked_2d drain) --------------------------
     def _call_rows2d(self, d: DescriptorArray, src: jax.Array,
-                     dst: jax.Array) -> Optional[jax.Array]:
+                     dst: jax.Array, tracer=None) -> Optional[jax.Array]:
         from repro.kernels.ops import descriptor_copy_bucketed_op
 
         if self.sig.transform:
             return None   # fused 2-D batches are identity-only
-        shape = dst.shape
-        src2 = src.reshape(src.shape[0], -1)
-        dst2 = dst.reshape(dst.shape[0], -1)
-        if src2.shape[1] != dst2.shape[1] or src2.dtype != dst2.dtype:
+        if math.prod(src.shape[1:]) != math.prod(dst.shape[1:]) \
+                or src.dtype != dst.dtype:
             return None
-        active = np.asarray(d.length) >= 0
-        sidx = np.where(active, np.asarray(d.src, np.int32), -1)
-        didx = np.where(active, np.asarray(d.dst, np.int32), -1)
+        with (NO_SPAN if tracer is None else
+              tracer.span("drain.pull", "translation", ring=False)):
+            active = np.asarray(d.length) >= 0
+            sidx = np.where(active, np.asarray(d.src, np.int32), -1)
+            didx = np.where(active, np.asarray(d.dst, np.int32), -1)
+        if tracer is not None:
+            tracer.count("d2h_reads", 3)
         self.dispatches += 1
-        out = descriptor_copy_bucketed_op(
-            jnp.asarray(sidx), jnp.asarray(didx), src2, dst2,
-            n_bucket=self.sig.n_class)
-        return out.reshape(shape)
+        with (NO_SPAN if tracer is None else
+              tracer.span("drain.enqueue", "translation", ring=False)):
+            out = descriptor_copy_bucketed_op(
+                jnp.asarray(sidx), jnp.asarray(didx),
+                src.reshape(src.shape[0], -1), dst.reshape(dst.shape[0], -1),
+                n_bucket=self.sig.n_class)
+            return out.reshape(dst.shape)
 
     # -- linear-pool artifacts (serial tier) ---------------------------------
     def __call__(self, d: DescriptorArray, src: jax.Array, dst: jax.Array,
-                 *, max_len: int = 0) -> Optional[jax.Array]:
+                 *, max_len: int = 0, tracer=None) -> Optional[jax.Array]:
         if self.mode == "rows2d":
-            return self._call_rows2d(d, src, dst)
+            return self._call_rows2d(d, src, dst, tracer)
         n = d.num_descriptors
         if n > self.sig.n_class or src.ndim != 1 or dst.ndim != 1 \
                 or src.dtype != dst.dtype:
             return None
-        so = np.asarray(d.src, np.int32)
-        do = np.asarray(d.dst, np.int32)
-        ln = np.asarray(d.length, np.int32)
+        with (NO_SPAN if tracer is None else
+              tracer.span("drain.pull", "translation", ring=False)):
+            so = np.asarray(d.src, np.int32)
+            do = np.asarray(d.dst, np.int32)
+            ln = np.asarray(d.length, np.int32)
+        if tracer is not None:
+            tracer.count("d2h_reads", 3)
         if n and max_len > 0:
             # Legacy-fidelity guard: execute_serial copies through a fixed
             # max_len window whose dynamic_slice clamps near the pool tail,
@@ -247,20 +260,25 @@ class LoweredChain:
             if not _interpret() and (token == "" or kv8_ok):
                 # Uniform aligned units on TPU: whole-row moves through the
                 # Pallas mega-kernel over the unit-reshaped pools.
-                sidx = jnp.asarray(np.where(ln == unit, so // unit, -1))
-                didx = jnp.asarray(np.where(ln == unit, do // unit, -1))
                 self.dispatches += 1
                 op = (quantize_copy_bucketed_op if token == "kv8"
                       else descriptor_copy_bucketed_op)
-                out = op(sidx, didx, src.reshape(-1, unit),
-                         dst.reshape(-1, unit), n_bucket=self.sig.n_class)
-                return out.reshape(dst.shape)
+                with (NO_SPAN if tracer is None else
+                      tracer.span("drain.enqueue", "translation",
+                                  ring=False)):
+                    sidx = jnp.asarray(np.where(ln == unit, so // unit, -1))
+                    didx = jnp.asarray(np.where(ln == unit, do // unit, -1))
+                    out = op(sidx, didx, src.reshape(-1, unit),
+                             dst.reshape(-1, unit), n_bucket=self.sig.n_class)
+                    return out.reshape(dst.shape)
         fn = _EXEC.get((self.mode, token))
         if fn is None:
             return None
         self.dispatches += 1
-        return fn(jnp.asarray(so), jnp.asarray(do), jnp.asarray(ln),
-                  src, dst, width=self.sig.unit_class)
+        with (NO_SPAN if tracer is None else
+              tracer.span("drain.enqueue", "translation", ring=False)):
+            return fn(jnp.asarray(so), jnp.asarray(do), jnp.asarray(ln),
+                      src, dst, width=self.sig.unit_class)
 
 
 # ---------------------------------------------------------------------------
@@ -505,57 +523,61 @@ class TranslationCache:
         tr = self.tracer
         rec = tr is not None and tr.sampled(self.plan_hits
                                             + self.plan_misses)
-        p0 = monotonic() if rec else 0.0
-        canon = canonicalize(d, head)
-        if canon is None:
-            return None
-        key = (canon.digest, int(max_len), allow_merge)
-        plan = self._plans.get(key)
-        plan_was_hit = plan is not None
-        if plan is not None:
-            self._plans.move_to_end(key)
-            self.plan_hits += 1
-            self._event("plan_hit")
-        else:
-            plan = _plan_relative(canon, max_len, allow_merge)
-            self._plans[key] = plan
-            self.plan_misses += 1
-            self._event("plan_miss")
-            while len(self._plans) > self.plan_entries:
-                self._plans.popitem(last=False)
+        with (NO_SPAN if tr is None else
+              tr.span("translate.plan", self.track, ring=rec)) as sp:
+            canon = canonicalize(d, head)
+            if tr is not None:
+                tr.count("d2h_reads",
+                         CANONICALIZE_READS if canon is not None else 1)
+            if canon is None:
+                if rec:
+                    sp.ring = False
+                return None
+            key = (canon.digest, int(max_len), allow_merge)
+            plan = self._plans.get(key)
+            plan_was_hit = plan is not None
+            if plan is not None:
+                self._plans.move_to_end(key)
+                self.plan_hits += 1
+                self._event("plan_hit")
+            else:
+                plan = _plan_relative(canon, max_len, allow_merge)
+                self._plans[key] = plan
+                self.plan_misses += 1
+                self._event("plan_miss")
+                while len(self._plans) > self.plan_entries:
+                    self._plans.popitem(last=False)
 
-        if plan.n_out == 0:
-            planned = DescriptorArray.create(
-                np.zeros(0, np.int64), np.zeros(0, np.int64),
-                np.zeros(0, np.int64))
-        else:
-            planned = DescriptorArray.create(
-                plan.rel_src + canon.src_base,
-                plan.rel_dst + canon.dst_base,
-                plan.length, config=plan.config)
-        stats = CoalesceStats(
-            n_in=plan.n_in, n_out=plan.n_out, merged=plan.merged,
-            split=plan.split, input_hit_rate=plan.in_hit,
-            output_hit_rate=plan.out_hit, provisioned_slack=spec_depth)
-        sig = dataclasses.replace(
-            plan.sig0, tier=tier,
-            depth_class=pow2_bucket(spec_depth) if spec_depth else 0,
-            transform=token)
-        fuseable = token in FUSEABLE_TOKENS
-        lowered = self.lower(sig) \
-            if tier == "serial" and plan.n_out and fuseable else None
-        if token:
-            self.transform_lookups += 1
-            self._event("transform_lookup")
-            if lowered is not None:
-                self.transform_fused += 1
-                self._event("transform_fused")
-        if rec:
-            tr.complete("translate.plan", self.track, p0 * 1e6,
-                        (monotonic() - p0) * 1e6,
-                        result="plan_hit" if plan_was_hit else "plan_miss",
-                        digest=canon.digest[:6].hex(),
-                        n_out=plan.n_out)
+            if plan.n_out == 0:
+                planned = DescriptorArray.create(
+                    np.zeros(0, np.int64), np.zeros(0, np.int64),
+                    np.zeros(0, np.int64))
+            else:
+                planned = DescriptorArray.create(
+                    plan.rel_src + canon.src_base,
+                    plan.rel_dst + canon.dst_base,
+                    plan.length, config=plan.config)
+            stats = CoalesceStats(
+                n_in=plan.n_in, n_out=plan.n_out, merged=plan.merged,
+                split=plan.split, input_hit_rate=plan.in_hit,
+                output_hit_rate=plan.out_hit, provisioned_slack=spec_depth)
+            sig = dataclasses.replace(
+                plan.sig0, tier=tier,
+                depth_class=pow2_bucket(spec_depth) if spec_depth else 0,
+                transform=token)
+            fuseable = token in FUSEABLE_TOKENS
+            lowered = self.lower(sig) \
+                if tier == "serial" and plan.n_out and fuseable else None
+            if token:
+                self.transform_lookups += 1
+                self._event("transform_lookup")
+                if lowered is not None:
+                    self.transform_fused += 1
+                    self._event("transform_fused")
+            if rec:
+                sp.args.update(
+                    result="plan_hit" if plan_was_hit else "plan_miss",
+                    digest=canon.digest[:6].hex(), n_out=plan.n_out)
         return PlanResult(planned, stats, sig, lowered, canon.digest)
 
     # -- artifact LRU --------------------------------------------------------
@@ -571,13 +593,12 @@ class TranslationCache:
             if rec:
                 tr.instant("translate.hit", self.track, tier=sig.tier)
             return art
-        t0 = monotonic() if rec else 0.0
-        art = LoweredChain(sig)
-        self.misses += 1
-        self._event("miss")
-        if rec:
-            tr.complete("translate.compile", self.track, t0 * 1e6,
-                        (monotonic() - t0) * 1e6, tier=sig.tier)
+        with (NO_SPAN if tr is None else
+              tr.span("translate.compile", self.track, ring=rec,
+                      tier=sig.tier)):
+            art = LoweredChain(sig)
+            self.misses += 1
+            self._event("miss")
         self._artifacts[sig] = art
         while len(self._artifacts) > self.max_entries:
             self._artifacts.popitem(last=False)
@@ -602,14 +623,19 @@ class TranslationCache:
                 != dst.reshape(dst.shape[0], -1).shape[1] \
                 or src.dtype != dst.dtype:
             return None
-        ad = np.asarray(d.dst)[np.asarray(d.length) >= 0]
+        tr = self.tracer
+        with (NO_SPAN if tr is None else
+              tr.span("drain.pull", self.track, ring=False)):
+            ad = np.asarray(d.dst)[np.asarray(d.length) >= 0]
+        if tr is not None:
+            tr.count("d2h_reads", 2)
         if np.unique(ad).size != ad.size:
             return None
         sig = ChainSignature(
             tier="blocked_2d", n_class=pow2_bucket(d.num_descriptors),
             unit_class=1, layout="gather", unit=1, overlap=False,
             aligned=True, depth_class=0)
-        return self.lower(sig)(d, src, dst)
+        return self.lower(sig)(d, src, dst, tracer=tr)
 
     # -- memoized chain-shape predicates (scheduler satellites) --------------
     def is_sequential(self, d: DescriptorArray) -> bool:

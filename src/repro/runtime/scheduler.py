@@ -41,7 +41,7 @@ from repro.core.speculation import (
 from repro.core.transform import TransformSpec, as_transform
 
 from repro.obs.counters import PerfCounters, namespaced
-from repro.obs.trace import Tracer, monotonic
+from repro.obs.trace import NO_SPAN, Tracer, monotonic
 
 from .channel import (
     Channel,
@@ -260,126 +260,133 @@ class DMARuntime:
         name = channel if channel is not None \
             else self._pick_channel(tier, priority)
         ch = self.channels[name]
+        with (NO_SPAN if tr is None else
+              tr.span("submit", ch.track, ring=rec, ticket=first_ticket,
+                      channel=name, n_in=n_raw)) as sub:
+            stats: Optional[CoalesceStats] = None
+            lowered = None
+            if run_coalescer is None:
+                # Row-move and control streams have positional semantics
+                # the merge pass must not disturb; linear-byte tiers benefit.
+                run_coalescer = ch.cfg.tier in ("serial", "blocked")
+            if run_coalescer and d.num_descriptors:
+                with (NO_SPAN if tr is None else
+                      tr.span("coalesce", ch.track, ring=rec,
+                              ticket=first_ticket)) as co:
+                    d, stats, lowered = self._coalesce(d, ch, spec)
+                    if rec:
+                        co.args.update(n_in=stats.n_in, n_out=stats.n_out,
+                                       hit_rate=stats.input_hit_rate)
 
-        stats: Optional[CoalesceStats] = None
-        lowered = None
-        if run_coalescer is None:
-            # Row-move and control streams have positional semantics the
-            # merge pass must not disturb; linear-byte tiers benefit.
-            run_coalescer = ch.cfg.tier in ("serial", "blocked")
-        if run_coalescer and d.num_descriptors:
-            max_len = (ch.cfg.max_len if ch.cfg.tier == "serial"
-                       else min(ch.cfg.unit, self.coalesce_max_len)
-                       if ch.cfg.tier == "blocked" else self.coalesce_max_len)
-            # Ask-then-observe (DESIGN.md §5): the planner provisions the
-            # layout slack the channel's policy currently wants, then the
-            # measured input hit rate feeds back and may move the depth —
-            # for the *next* submission, never this one.
-            c0 = monotonic() if rec else 0.0
-            planned = None
-            if self.translation is not None:
-                # Chain-lowering fast path (DESIGN.md §7): plan through
-                # the digest-keyed memo (bit-identical to coalesce) and
-                # pick up the signature's compiled drain executor. A None
-                # plan (malformed chain) falls back to the legacy walker,
-                # which raises the canonical error.
-                planned = self.translation.plan(
-                    d, max_len=max_len, spec_depth=ch.speculation_depth,
-                    tier=ch.cfg.tier, transform=spec)
-            if planned is not None:
-                d, stats, lowered = (planned.planned, planned.stats,
-                                     planned.lowered)
-            else:
-                d, stats = coalesce(d, max_len=max_len,
-                                    spec_depth=ch.speculation_depth,
-                                    allow_merge=spec.merge_safe)
-            self.coalesce_in += stats.n_in
-            self.coalesce_out += stats.n_out
-            self._hit_rates.append(stats.input_hit_rate)
-            ch.observe_speculation(stats.input_hit_rate)
-            if rec:
-                tr.complete("coalesce", ch.track, c0 * 1e6,
-                            (monotonic() - c0) * 1e6,
-                            ticket=first_ticket, n_in=stats.n_in,
-                            n_out=stats.n_out,
-                            hit_rate=stats.input_hit_rate,
-                            planned=planned is not None)
+            n = d.num_descriptors
+            if n == 0:
+                dt = monotonic() - t0
+                if self.probe is not None:
+                    self.probe.on_submit(
+                        name, n_in=n_raw, n_out=0, launch_seconds=dt,
+                        hit_rate=stats.input_hit_rate if stats else None)
+                if rec:
+                    sub.args["n_out"] = 0
+                return Ticket([], name, False, stats,
+                              transform=spec.cache_token)
 
-        n = d.num_descriptors
-        if n == 0:
-            dt = monotonic() - t0
+            # A chain longer than the ring is submitted in ring-sized
+            # pieces (the driver can never map more descriptors than slots
+            # at once). Safe when execution order across pieces equals
+            # chain order: true for sequentially-chained streams (every
+            # coalesced chain) and for the order-free blocked tiers; a
+            # serial-tier chain with arbitrary `nxt` links cannot be cut,
+            # so reject it loudly instead of hanging.
+            chunks = [d]
+            if n > ch.ring.capacity:
+                sequential = (self.translation.is_sequential(d)
+                              if self.translation is not None
+                              else _is_sequential_chain(d))
+                if ch.cfg.tier == "serial" and not sequential:
+                    raise ValueError(
+                        f"chain of {n} descriptors exceeds ring capacity "
+                        f"{ch.ring.capacity} and is not sequentially "
+                        "linked; coalesce it or enlarge the ring")
+                chunks = _split_chain(d, ch.ring.capacity)
+                lowered = None   # pieces have new shapes; drain them legacy
+
+            tickets = self._take_tickets(n, name)
+            if on_complete is not None:
+                self.completion.register(tickets[-1], on_complete)
+
+            spilled = False
+            cursor = 0
+            for piece in chunks:
+                k = piece.num_descriptors
+                piece_tickets = tickets[cursor:cursor + k]
+                cursor += k
+                while True:
+                    try:
+                        ch.submit(SubmitRequest(chain=piece,
+                                                src_pool=src_pool,
+                                                dst_pool=dst_pool,
+                                                transform=spec),
+                                  piece_tickets, lowered=lowered)
+                        break
+                    except RingFull:
+                        if self.backpressure == "block":
+                            # Paper driver semantics: the submitter waits
+                            # on the device; "waiting" = advancing the
+                            # consumer.
+                            if not ch.drain_one(self.pools) \
+                                    and ch.ring.full:
+                                raise  # ring full of unacknowledged work
+                        else:
+                            self._spill.append(_Spilled(
+                                piece, piece_tickets, name, src_pool,
+                                dst_pool, spec))
+                            spilled = True
+                            break
+            self.submitted_descriptors += n
+            launch = monotonic() - t0
+            self.launch_seconds += launch
             if self.probe is not None:
                 self.probe.on_submit(
-                    name, n_in=n_raw, n_out=0, launch_seconds=dt,
+                    name, n_in=n_raw, n_out=n, launch_seconds=launch,
                     hit_rate=stats.input_hit_rate if stats else None)
             if rec:
-                tr.complete("submit", ch.track, t0 * 1e6, dt * 1e6,
-                            ticket=first_ticket, channel=name,
-                            n_in=n_raw, n_out=0)
-            return Ticket([], name, False, stats,
+                sub.args.update(n_out=n, spilled=spilled)
+            return Ticket(tickets, name, spilled, stats,
                           transform=spec.cache_token)
 
-        # A chain longer than the ring is submitted in ring-sized pieces
-        # (the driver can never map more descriptors than slots at once).
-        # Safe when execution order across pieces equals chain order: true
-        # for sequentially-chained streams (every coalesced chain) and for
-        # the order-free blocked tiers; a serial-tier chain with arbitrary
-        # `nxt` links cannot be cut, so reject it loudly instead of hanging.
-        chunks = [d]
-        if n > ch.ring.capacity:
-            sequential = (self.translation.is_sequential(d)
-                          if self.translation is not None
-                          else _is_sequential_chain(d))
-            if ch.cfg.tier == "serial" and not sequential:
-                raise ValueError(
-                    f"chain of {n} descriptors exceeds ring capacity "
-                    f"{ch.ring.capacity} and is not sequentially linked; "
-                    "coalesce it or enlarge the ring")
-            chunks = _split_chain(d, ch.ring.capacity)
-            lowered = None   # pieces have new shapes; drain them legacy
-
-        tickets = self._take_tickets(n, name)
-        if on_complete is not None:
-            self.completion.register(tickets[-1], on_complete)
-
-        spilled = False
-        cursor = 0
-        for piece in chunks:
-            k = piece.num_descriptors
-            piece_tickets = tickets[cursor:cursor + k]
-            cursor += k
-            while True:
-                try:
-                    ch.submit(SubmitRequest(chain=piece, src_pool=src_pool,
-                                            dst_pool=dst_pool,
-                                            transform=spec),
-                              piece_tickets, lowered=lowered)
-                    break
-                except RingFull:
-                    if self.backpressure == "block":
-                        # Paper driver semantics: the submitter waits on
-                        # the device; "waiting" = advancing the consumer.
-                        if not ch.drain_one(self.pools) and ch.ring.full:
-                            raise  # ring full of unacknowledged work
-                    else:
-                        self._spill.append(_Spilled(
-                            piece, piece_tickets, name, src_pool, dst_pool,
-                            spec))
-                        spilled = True
-                        break
-        self.submitted_descriptors += n
-        launch = monotonic() - t0
-        self.launch_seconds += launch
-        if self.probe is not None:
-            self.probe.on_submit(
-                name, n_in=n_raw, n_out=n, launch_seconds=launch,
-                hit_rate=stats.input_hit_rate if stats else None)
-        if rec:
-            tr.complete("submit", ch.track, t0 * 1e6, launch * 1e6,
-                        ticket=tickets[0], channel=name,
-                        n_in=n_raw, n_out=n, spilled=spilled)
-        return Ticket(tickets, name, spilled, stats,
-                      transform=spec.cache_token)
+    def _coalesce(self, d: DescriptorArray, ch: Channel,
+                  spec: TransformSpec):
+        """Plan ``d`` for ``ch``: ``(planned chain, stats, lowered)``."""
+        max_len = (ch.cfg.max_len if ch.cfg.tier == "serial"
+                   else min(ch.cfg.unit, self.coalesce_max_len)
+                   if ch.cfg.tier == "blocked" else self.coalesce_max_len)
+        # Ask-then-observe (DESIGN.md §5): the planner provisions the
+        # layout slack the channel's policy currently wants, then the
+        # measured input hit rate feeds back and may move the depth —
+        # for the *next* submission, never this one.
+        planned = None
+        lowered = None
+        if self.translation is not None:
+            # Chain-lowering fast path (DESIGN.md §7): plan through the
+            # digest-keyed memo (bit-identical to coalesce) and pick up the
+            # signature's compiled drain executor. A None plan (malformed
+            # chain) falls back to the legacy walker, which raises the
+            # canonical error.
+            planned = self.translation.plan(
+                d, max_len=max_len, spec_depth=ch.speculation_depth,
+                tier=ch.cfg.tier, transform=spec)
+        if planned is not None:
+            d, stats, lowered = (planned.planned, planned.stats,
+                                 planned.lowered)
+        else:
+            d, stats = coalesce(d, max_len=max_len,
+                                spec_depth=ch.speculation_depth,
+                                allow_merge=spec.merge_safe)
+        self.coalesce_in += stats.n_in
+        self.coalesce_out += stats.n_out
+        self._hit_rates.append(stats.input_hit_rate)
+        ch.observe_speculation(stats.input_hit_rate)
+        return d, stats, lowered
 
     def submit_control(self, payload: int = 0, *,
                        channel: Optional[str] = None,
@@ -456,24 +463,43 @@ class DMARuntime:
                 b = ch.pending.popleft()
                 groups.setdefault((b.src_pool, b.dst_pool), []).append((ch, b))
         ran = 0
+        tr = self.tracer
         for (src_name, dst_name), items in groups.items():
-            # Fusion executes every batch's reads against the pre-drain
-            # pool, so a batch that reads (RAW) or rewrites (WAW) a row an
-            # earlier fused batch wrote must start a new fused call.
-            sub: List[Tuple[Channel, object]] = []
-            written: set = set()
-            for ch, b in items:
+            first = items[0][1].tickets
+            rec = tr is not None and bool(first) and tr.sampled(first[0])
+            with (NO_SPAN if tr is None else
+                  tr.span("drain", items[0][0].track, ring=rec,
+                          ticket=first[0] if first else None,
+                          n=sum(b.descs.num_descriptors for _, b in items),
+                          fused=True)):
+                ran += self._drain_group(items, src_name, dst_name)
+        return ran
+
+    def _drain_group(self, items: List[Tuple[Channel, object]],
+                     src_name: str, dst_name: str) -> int:
+        # Fusion executes every batch's reads against the pre-drain pool,
+        # so a batch that reads (RAW) or rewrites (WAW) a row an earlier
+        # fused batch wrote must start a new fused call.
+        tr = self.tracer
+        ran = 0
+        sub: List[Tuple[Channel, object]] = []
+        written: set = set()
+        for ch, b in items:
+            with (NO_SPAN if tr is None else
+                  tr.span("drain.pull", ch.track, ring=False)):
                 src_rows = set(np.asarray(b.descs.src).tolist())
                 dst_rows = set(np.asarray(b.descs.dst).tolist())
-                if sub and (src_rows & written or dst_rows & written):
-                    self._execute_fused(sub, src_name, dst_name)
-                    ran += len(sub)
-                    sub, written = [], set()
-                sub.append((ch, b))
-                written |= dst_rows
-            if sub:
+            if tr is not None:
+                tr.count("d2h_reads", 2)
+            if sub and (src_rows & written or dst_rows & written):
                 self._execute_fused(sub, src_name, dst_name)
                 ran += len(sub)
+                sub, written = [], set()
+            sub.append((ch, b))
+            written |= dst_rows
+        if sub:
+            self._execute_fused(sub, src_name, dst_name)
+            ran += len(sub)
         return ran
 
     def _execute_fused(self, items: List[Tuple[Channel, object]],
@@ -488,6 +514,7 @@ class DMARuntime:
         )
         t0 = monotonic()
         out = None
+        tr = self.tracer
         if self.translation is not None:
             # Lowered fused drain: the whole multi-channel batch through
             # one bucketed Pallas mega-kernel (declines off-TPU and on
@@ -495,16 +522,12 @@ class DMARuntime:
             out = self.translation.execute_rows_2d(
                 fused, self.pools[src_name], self.pools[dst_name])
         if out is None:
-            out, _ = execute_blocked_2d(
-                fused, self.pools[src_name], self.pools[dst_name])
+            with (NO_SPAN if tr is None else
+                  tr.span("drain.enqueue", items[0][0].track, ring=False)):
+                out, _ = execute_blocked_2d(
+                    fused, self.pools[src_name], self.pools[dst_name])
         dt = monotonic() - t0
         self.pools[dst_name] = out
-        tr = self.tracer
-        if tr is not None and items[0][1].tickets \
-                and tr.sampled(items[0][1].tickets[0]):
-            tr.complete("drain", items[0][0].track, t0 * 1e6, dt * 1e6,
-                        ticket=items[0][1].tickets[0],
-                        n=fused.num_descriptors, fused=True)
         # The fused call's wall-clock is apportioned per batch by descriptor
         # share, so per-channel drain_seconds stay comparable across paths.
         total = max(fused.num_descriptors, 1)

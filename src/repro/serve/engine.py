@@ -25,7 +25,7 @@ from repro.models import DecodeState, decode_step
 from repro.models.transformer import init_decode_caches
 from repro.obs.counters import PerfCounters, namespaced
 from repro.obs.metrics import Histogram
-from repro.obs.trace import Tracer, monotonic
+from repro.obs.trace import NO_SPAN, Tracer, monotonic
 from repro.runtime import ChannelConfig, DMARuntime
 from repro.runtime.instrumentation import PerfProbe
 from repro.runtime.submit import SubmitRequest, Ticket, reject_legacy_submit
@@ -280,6 +280,8 @@ class ServeEngine:
                 slot.request = self.queue.popleft()
                 slot.prompt_cursor = 0
                 self._reset_slot_caches(b)
+                if self.tracer is not None:
+                    self.tracer.count("admissions")
         if self.queue:
             # Admission stall: requests are waiting but every slot is busy
             # — the continuous-batching pressure signal the perf sweep
@@ -289,11 +291,34 @@ class ServeEngine:
                 self.probe.on_admission_stall()
 
     def step(self) -> None:
+        """One decode step over every occupied slot, after admission.
+
+        With a tracer, the step is a ``serve.step`` span holding
+        ``serve.admit`` (admission), ``serve.dispatch`` (the decode
+        program's call) and ``serve.sync`` (the sampled tokens and
+        positions read back to the host).
+        """
+        tr = self.tracer
+        if tr is None:
+            self._step(None)
+            return
+        with tr.span("serve.step", self.track, ring=tr.sampled(self.steps),
+                     step=self.steps) as sp:
+            n_active = self._step(tr)
+            if n_active is None:
+                sp.ring = False
+            else:
+                sp.args["active_slots"] = n_active
+
+    def _step(self, tr: Optional[Tracer]) -> Optional[int]:
+        """The body of :meth:`step`; the occupied slots, None when idle."""
         t0 = monotonic()
-        self._admit()
+        with (NO_SPAN if tr is None else
+              tr.span("serve.admit", self.track, ring=False)):
+            self._admit()
         active = np.array([s.busy for s in self.slots])
         if not active.any():
-            return
+            return None
         tokens = np.zeros((self.capacity,), np.int32)
         for b, slot in enumerate(self.slots):
             if not slot.busy:
@@ -304,16 +329,19 @@ class ServeEngine:
             else:
                 tokens[b] = r.output[-1] if r.output else 0
 
-        logits, new_state = self._step_fn(self.params,
-                                          jnp.asarray(tokens), self.state)
-        sampled = np.asarray(jnp.argmax(logits, axis=-1))
-
-        # Advance only active slots (inactive ring writes are invalidated on
-        # admit via tag reset).
-        cur = np.asarray(new_state.cur_pos)
-        cur = np.where(active, cur, np.asarray(self.state.cur_pos))
-        self.state = DecodeState(new_state.caches,
-                                 jnp.asarray(cur, jnp.int32))
+        with (NO_SPAN if tr is None else
+              tr.span("serve.dispatch", self.track, ring=False)):
+            logits, new_state = self._step_fn(self.params,
+                                              jnp.asarray(tokens), self.state)
+        with (NO_SPAN if tr is None else
+              tr.span("serve.sync", self.track, ring=False)):
+            sampled = np.asarray(jnp.argmax(logits, axis=-1))
+            # Advance only active slots (inactive ring writes are
+            # invalidated on admit via tag reset).
+            cur = np.asarray(new_state.cur_pos)
+            cur = np.where(active, cur, np.asarray(self.state.cur_pos))
+            self.state = DecodeState(new_state.caches,
+                                     jnp.asarray(cur, jnp.int32))
 
         for b, slot in enumerate(self.slots):
             if not slot.busy:
@@ -340,7 +368,6 @@ class ServeEngine:
                 self.request_latency.record(latency)
                 if self.probe is not None:
                     self.probe.on_request_latency(latency)
-                tr = self.tracer
                 if tr is not None and tr.sampled(r.uid):
                     tr.instant("writeback", self.track, uid=r.uid,
                                ticket=self._tickets[r.uid])
@@ -354,7 +381,4 @@ class ServeEngine:
         self.active_slot_steps += n_active
         if self.probe is not None:
             self.probe.on_serve_step(n_active, dt)
-        tr = self.tracer
-        if tr is not None and tr.sampled(self.steps - 1):
-            tr.complete("serve.step", self.track, t0 * 1e6, dt * 1e6,
-                        step=self.steps - 1, active_slots=n_active)
+        return n_active

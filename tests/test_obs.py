@@ -392,3 +392,216 @@ def test_disabled_tracer_dispatch_overhead_within_two_percent():
             return
     pytest.fail(f"disabled-tracer dispatch overhead exceeded 2% in every "
                 f"attempt: ratios={[f'{r:.4f}' for r in ratios]}")
+
+
+# ---------------------------------------------------------------------------
+# Profiler-clock spans: exact totals, counters, the runtime's span tree
+# ---------------------------------------------------------------------------
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each span
+    entered with its parent (the innermost span open at the time)."""
+
+    def __init__(self):
+        self.entered = []          # (name, parent or None)
+        self.kwargs = []
+        self._open = []
+
+    def __call__(self, name, **kwargs):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.entered.append((name, rec._open[-1] if rec._open
+                                    else None))
+                rec.kwargs.append(kwargs)
+                rec._open.append(name)
+
+            def __exit__(self, *exc):
+                assert rec._open.pop() == name
+
+        return _Ann()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import repro.obs.trace as trace_mod
+    rec = _Annotations()
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", rec)
+    return rec
+
+
+def test_span_totals_count_total_and_self_time(annotations, monkeypatch):
+    import repro.obs.trace as trace_mod
+    clock = iter([0.0, 1.0, 3.0, 3.5, 4.0, 6.0])
+    monkeypatch.setattr(trace_mod, "monotonic", lambda: next(clock))
+    tr = Tracer()
+    with tr.span("outer", "t"):           # 0.0 .. 6.0
+        with tr.span("inner", "t"):       # 1.0 .. 3.0
+            pass
+        with tr.span("inner", "t"):       # 3.5 .. 4.0
+            pass
+    spans = tr.totals()["spans"]
+    assert spans["outer"] == {"count": 1, "total_s": 6.0, "self_s": 3.5}
+    assert spans["inner"] == {"count": 2, "total_s": 2.5, "self_s": 2.5}
+    # the profiler sees the bare names, nested, with no metadata
+    assert annotations.entered == [("outer", None), ("inner", "outer"),
+                                   ("inner", "outer")]
+    assert annotations.kwargs == [{}, {}, {}]
+
+
+def test_counters_and_clear_reset_ring_and_totals(annotations):
+    tr = Tracer()
+    tr.count("d2h_reads", 3)
+    tr.count("d2h_reads")
+    tr.count("admissions")
+    with tr.span("a", "t", n=1):
+        pass
+    assert tr.totals()["counters"] == {"d2h_reads": 4, "admissions": 1}
+    assert tr.totals()["spans"]["a"]["count"] == 1 and len(tr.events()) == 1
+    tr.clear()
+    assert tr.totals() == {"spans": {}, "counters": {}}
+    assert tr.events() == [] and tr.emitted == 0
+
+
+def test_totals_stay_exact_when_the_ring_drops(annotations):
+    tr = Tracer(capacity=2)
+    for _ in range(10):
+        with tr.span("s", "t"):
+            pass
+    with tr.span("unsampled", "t", ring=False):
+        pass
+    assert len(tr.events()) == 2 and tr.dropped == 8
+    spans = tr.totals()["spans"]
+    assert spans["s"]["count"] == 10 and spans["unsampled"]["count"] == 1
+    assert [e.name for e in tr.events()] == ["s", "s"]
+
+
+def _serial_runtime():
+    import jax.numpy as jnp
+
+    from repro.runtime import ChannelConfig, DMARuntime
+    rt = DMARuntime([ChannelConfig("kv", tier="serial", max_len=8,
+                                   ring_capacity=64)])
+    rt.register_pool("src", jnp.arange(256, dtype=jnp.float32))
+    rt.register_pool("dst", jnp.zeros(256, jnp.float32))
+    return rt
+
+
+def _serial_chain():
+    from repro.core.chain import from_segments
+    return from_segments(np.array([0, 8, 32]), np.array([64, 72, 80]),
+                         np.array([8, 8, 8]))
+
+
+def _round(rt, chain, src="src", dst="dst", channel=None):
+    from repro.runtime import SubmitRequest
+    rt.submit(SubmitRequest(chain=chain, src_pool=src, dst_pool=dst,
+                            channel=channel, on_complete=lambda r: None))
+    rt.drain_until_idle()
+    return rt.poll()
+
+
+def test_no_annotation_without_a_tracer(annotations):
+    rt = _serial_runtime()
+    assert _round(rt, _serial_chain())
+    assert annotations.entered == []
+
+
+def _rows_runtime(use_kernel):
+    import jax.numpy as jnp
+
+    from repro.runtime import ChannelConfig, DMARuntime
+    rt = DMARuntime([ChannelConfig("rows", tier="blocked_2d",
+                                   use_kernel=use_kernel, ring_capacity=64)])
+    rt.register_pool("src", jnp.arange(32 * 128, dtype=jnp.float32)
+                     .reshape(32, 128))
+    rt.register_pool("dst", jnp.zeros((32, 128), jnp.float32))
+    return rt
+
+
+def _rows_chain():
+    from repro.core.descriptor import DescriptorArray
+    return DescriptorArray.create([3, 7, 1, 20], [0, 5, 9, 30],
+                                  [1, 1, 1, 1])
+
+
+# Each case: the runtime, its chain, the spans entered with their parents,
+# and the reads back to the host that d2h_reads counts for it.
+_CASES = {
+    # canonicalize 5 + to_packed 7 + irq 1 + LoweredChain operands 3
+    "serial": (_serial_runtime, _serial_chain, {
+        ("submit", None), ("coalesce", "submit"),
+        ("translate.plan", "coalesce"),
+        ("translate.compile", "translate.plan"),
+        ("ring.push", "submit"), ("ring.pack", "ring.push"),
+        ("drain", None), ("drain.pull", "drain"),
+        ("drain.enqueue", "drain"), ("completion.poll", None)}, 16),
+    # to_packed 7 + irq 1 + the kernel channel's length read 1
+    "blocked_2d_kernel": (lambda: _rows_runtime(True), _rows_chain, {
+        ("submit", None), ("ring.push", "submit"),
+        ("ring.pack", "ring.push"), ("drain", None),
+        ("drain.pull", "drain"), ("drain.enqueue", "drain"),
+        ("completion.poll", None)}, 9),
+    # to_packed 7 + irq 1 + the fused drain's src and dst reads 2
+    "blocked_2d_fused": (lambda: _rows_runtime(False), _rows_chain, {
+        ("submit", None), ("ring.push", "submit"),
+        ("ring.pack", "ring.push"), ("drain", None),
+        ("drain.pull", "drain"), ("drain.enqueue", "drain"),
+        ("completion.poll", None)}, 10),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_runtime_span_tree_and_d2h_reads(annotations, case):
+    make_rt, make_chain, tree, reads = _CASES[case]
+    rt = make_rt()
+    want = rt.pools["dst"]
+    tr = Tracer()
+    rt.attach_tracer(tr)
+    assert _round(rt, make_chain())
+    assert set(annotations.entered) == tree
+    tot = tr.totals()
+    assert tot["counters"] == {"d2h_reads": reads}
+    assert tot["spans"]["submit"]["count"] == 1
+    assert tot["spans"]["drain"]["count"] == 1
+    assert all(v["self_s"] <= v["total_s"] for v in tot["spans"].values())
+    # the traced drain moved the same data as an untraced one
+    ref = make_rt()
+    _round(ref, make_chain())
+    np.testing.assert_array_equal(np.asarray(rt.pools["dst"]),
+                                  np.asarray(ref.pools["dst"]))
+    assert not np.array_equal(np.asarray(rt.pools["dst"]), np.asarray(want))
+
+
+def test_serve_step_spans_and_admissions(annotations):
+    import jax
+
+    from repro.configs.registry import get_config
+    from repro.models import init_params
+    from repro.runtime import SubmitRequest
+    from repro.serve import Request, ServeEngine
+
+    cfg = get_config("qwen2.5-3b", reduced=True)
+    eng = ServeEngine(init_params(jax.random.PRNGKey(0), cfg), cfg,
+                      capacity=2, max_len=16)
+    tr = Tracer()
+    eng.attach_tracer(tr)
+    for uid in range(3):
+        eng.submit(SubmitRequest(request=Request(
+            uid=uid, prompt=[1 + uid, 2], max_new_tokens=2)))
+    eng.run(max_steps=20)
+    assert len(eng.completed) == 3
+    assert {("serve.admit", "serve.step"), ("serve.dispatch", "serve.step"),
+            ("serve.sync", "serve.step")} <= set(annotations.entered)
+    tot = tr.totals()
+    assert tot["counters"]["admissions"] == 3
+    for name in ("serve.step", "serve.admit", "serve.dispatch",
+                 "serve.sync"):
+        assert tot["spans"][name]["count"] == eng.steps
+    step = tot["spans"]["serve.step"]
+    parts = sum(tot["spans"][n]["total_s"]
+                for n in ("serve.admit", "serve.dispatch", "serve.sync"))
+    assert step["self_s"] == pytest.approx(step["total_s"] - parts)
+    assert [e.name for e in tr.events() if e.name == "serve.step"] \
+        == ["serve.step"] * eng.steps
