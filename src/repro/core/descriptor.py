@@ -20,7 +20,10 @@ Two representations, round-trippable:
    not a flat byte space, so on-device descriptors address elements of a named
    (src_pool, dst_pool) pair. ``next`` holds the *index* of the successor
    descriptor in the table (-1 = end-of-chain), which is the natural device
-   analogue of the paper's next-pointer.
+   analogue of the paper's next-pointer. The same class holds NumPy fields
+   in its *host form* (:meth:`DescriptorArray.from_host`,
+   :meth:`DescriptorArray.to_host`): the runtime keeps a chain there from
+   ``submit`` to the drain, which uploads only the index vectors it runs.
 
 Completion tracking follows §II-D: the engine overwrites the first 8 bytes of
 a completed descriptor with all-ones (``DONE_SENTINEL``); on device this is a
@@ -146,42 +149,70 @@ class DescriptorArray:
     # -- constructors -------------------------------------------------------
     @classmethod
     def create(cls, src, dst, length, nxt=None, config=None) -> "DescriptorArray":
-        src = jnp.asarray(src, jnp.int32)
-        dst = jnp.asarray(dst, jnp.int32)
-        length = jnp.asarray(length, jnp.int32)
+        return cls._build(jnp, src, dst, length, nxt, config)
+
+    @classmethod
+    def from_host(cls, src, dst, length, nxt=None,
+                  config=None) -> "DescriptorArray":
+        """Host form: :meth:`create`'s six int32 fields, as NumPy arrays.
+
+        The runtime plans, packs and drains chains in this form, so none of
+        its own bookkeeping crosses to the device and back.
+        """
+        return cls._build(np, src, dst, length, nxt, config)
+
+    @classmethod
+    def _build(cls, xp, src, dst, length, nxt, config) -> "DescriptorArray":
+        """The fields as ``xp`` (``jnp`` or ``np``) int32 arrays."""
+        src = xp.asarray(src, xp.int32)
+        dst = xp.asarray(dst, xp.int32)
+        length = xp.asarray(length, xp.int32)
         n = src.shape[0]
         if nxt is None:  # default: sequential chain ending at -1
-            nxt = jnp.concatenate([jnp.arange(1, n, dtype=jnp.int32),
-                                   jnp.full((1,), -1, jnp.int32)])
+            nxt = xp.concatenate([xp.arange(1, n, dtype=xp.int32),
+                                  xp.full((1,), -1, xp.int32)])
         else:
-            nxt = jnp.asarray(nxt, jnp.int32)
+            nxt = xp.asarray(nxt, xp.int32)
         if config is None:
-            config = jnp.zeros((n,), jnp.int32)
+            config = xp.zeros((n,), xp.int32)
         else:
-            config = jnp.asarray(config, jnp.int32)
-        done = jnp.zeros((n,), jnp.int32)
+            config = xp.asarray(config, xp.int32)
+        done = xp.zeros((n,), xp.int32)
         return cls(src, dst, length, nxt, config, done)
+
+    @property
+    def on_host(self) -> bool:
+        """Whether every field is already a NumPy array (host form)."""
+        return all(isinstance(f, np.ndarray) for f in self.tree_flatten()[0])
+
+    def to_host(self) -> "DescriptorArray":
+        """This chain in host form: itself when :attr:`on_host`, otherwise
+        all six fields fetched in one :func:`jax.device_get`."""
+        return self if self.on_host else jax.device_get(self)
 
     @property
     def num_descriptors(self) -> int:
         return self.src.shape[0]
 
     def mark_done(self, idx) -> "DescriptorArray":
-        """Device analogue of the all-ones writeback."""
+        """Device analogue of the all-ones writeback (in the chain's form)."""
+        if self.on_host:
+            def put(field, value):
+                field = field.copy()
+                field[idx] = value
+                return field
+        else:
+            def put(field, value):
+                return field.at[idx].set(value)
         return dataclasses.replace(
             self,
-            done=self.done.at[idx].set(1),
-            length=self.length.at[idx].set(-1),
-            config=self.config.at[idx].set(-1),
+            done=put(self.done, 1),
+            length=put(self.length, -1),
+            config=put(self.config, -1),
         )
 
     def all_done(self) -> jax.Array:
         return jnp.all(self.done == 1)
-
-
-#: Conversions of a field to NumPy that :func:`to_packed` asks for (one
-#: per call; ``done`` is read twice): the runtime's ``d2h_reads`` counter.
-TO_PACKED_READS = 7
 
 
 def to_packed(
@@ -192,7 +223,7 @@ def to_packed(
     dst_base: int = 0,
     table_base: int = 0,
 ) -> np.ndarray:
-    """Lower a device SoA table to the packed 256-bit host layout.
+    """Lower an SoA table (either form) to the packed 256-bit host layout.
 
     Element offsets become byte addresses relative to the given pool bases;
     successor indices become byte addresses of descriptor slots (sequential

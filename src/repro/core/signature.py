@@ -127,11 +127,6 @@ class CanonicalChain:
         return h.digest()
 
 
-#: Conversions of a field to NumPy that :func:`canonicalize` asks for: one
-#: (``nxt``) when the walk fails, all five when it succeeds.
-CANONICALIZE_READS = 5
-
-
 def canonicalize(d: DescriptorArray,
                  head: int = 0) -> Optional[CanonicalChain]:
     """Walk-ordered relative form of a chain; None when the walk fails."""

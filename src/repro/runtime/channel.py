@@ -32,7 +32,6 @@ import numpy as np
 
 from repro.core.descriptor import (
     CONFIG_IRQ_ENABLE,
-    TO_PACKED_READS,
     DescriptorArray,
     to_packed,
 )
@@ -190,8 +189,6 @@ class Channel:
                   tr.span("ring.pack", self.track, ring=False)):
                 packed = to_packed(d)
                 irq = (np.asarray(d.config) & int(CONFIG_IRQ_ENABLE)) != 0
-            if tr is not None:
-                tr.count("d2h_reads", TO_PACKED_READS + 1)
             try:
                 slots = self.ring.push_table(packed, tickets, irq=irq)
             except RingFull:
@@ -228,16 +225,15 @@ class Channel:
             with (NO_SPAN if tr is None else
                   tr.span("drain.pull", self.track, ring=False)):
                 active = np.asarray(d.length) >= 0
-            if tr is not None:
-                tr.count("d2h_reads")
+                sidx = np.where(active, np.asarray(d.src), -1)
+                didx = np.where(active, np.asarray(d.dst), -1)
             with (NO_SPAN if tr is None else
                   tr.span("drain.enqueue", self.track, ring=False)):
                 shape = dst.shape
                 src2 = src.reshape(src.shape[0], -1)
                 dst2 = dst.reshape(dst.shape[0], -1)
-                sidx = jnp.where(jnp.asarray(active), d.src, -1)
-                didx = jnp.where(jnp.asarray(active), d.dst, -1)
-                return descriptor_copy_op(sidx, didx, src2,
+                return descriptor_copy_op(jnp.asarray(sidx),
+                                          jnp.asarray(didx), src2,
                                           dst2).reshape(shape)
         if tier not in ("serial", "blocked", "blocked_2d"):
             raise ValueError(f"tier {tier!r} carries no data")

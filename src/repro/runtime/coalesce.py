@@ -84,7 +84,8 @@ def coalesce(
     Returns ``(planned, stats)`` where ``planned`` executes bit-identically
     to ``d`` under serial chain semantics (same bytes moved in the same
     order), holds no descriptor longer than ``max_len``, and is chained
-    ``0 -> 1 -> ... -> n-1`` (sequential layout).
+    ``0 -> 1 -> ... -> n-1`` (sequential layout). ``planned`` is in host
+    form (:meth:`DescriptorArray.from_host`).
 
     ``spec_depth`` is the sequential-layout slack the caller's speculation
     policy asked for (DESIGN.md §5): the planner must guarantee a §II-C
@@ -161,14 +162,14 @@ def coalesce(
             first = False
 
     if not o_src:   # fully-sentinel input: keep a well-formed empty chain
-        planned = DescriptorArray.create(
+        planned = DescriptorArray.from_host(
             np.zeros(0, np.int64), np.zeros(0, np.int64), np.zeros(0, np.int64))
         stats = CoalesceStats(n_in, 0, merged, split, in_hit, 1.0,
                               provisioned_slack=spec_depth)
         return planned, stats
 
     # -- sequential layout: 0 -> 1 -> ... -> -1 (hits by construction) -----
-    planned = DescriptorArray.create(
+    planned = DescriptorArray.from_host(
         np.asarray(o_src, np.int64),
         np.asarray(o_dst, np.int64),
         np.asarray(o_len, np.int64),

@@ -55,7 +55,6 @@ from repro.core.descriptor import (
 )
 from repro.core.prefetch import estimate_hit_rate
 from repro.core.signature import (
-    CANONICALIZE_READS,
     CanonicalChain,
     ChainSignature,
     canonicalize,
@@ -176,8 +175,8 @@ class LoweredChain:
     dst' | None``; ``None`` means "not safe to substitute for the legacy
     engine here — run the legacy path". ``dispatches`` counts successful
     substitutions (one artifact, many dispatches, is the whole point). A
-    ``tracer`` times the descriptor reads back to the host (``drain.pull``)
-    apart from the dispatch of the copy program (``drain.enqueue``).
+    ``tracer`` times the host-side operand build (``drain.pull``) apart
+    from the upload and dispatch of the copy program (``drain.enqueue``).
     """
 
     def __init__(self, sig: ChainSignature):
@@ -205,8 +204,6 @@ class LoweredChain:
             active = np.asarray(d.length) >= 0
             sidx = np.where(active, np.asarray(d.src, np.int32), -1)
             didx = np.where(active, np.asarray(d.dst, np.int32), -1)
-        if tracer is not None:
-            tracer.count("d2h_reads", 3)
         self.dispatches += 1
         with (NO_SPAN if tracer is None else
               tracer.span("drain.enqueue", "translation", ring=False)):
@@ -230,8 +227,6 @@ class LoweredChain:
             so = np.asarray(d.src, np.int32)
             do = np.asarray(d.dst, np.int32)
             ln = np.asarray(d.length, np.int32)
-        if tracer is not None:
-            tracer.count("d2h_reads", 3)
         if n and max_len > 0:
             # Legacy-fidelity guard: execute_serial copies through a fixed
             # max_len window whose dynamic_slice clamps near the pool tail,
@@ -507,8 +502,8 @@ class TranslationCache:
              transform=None) -> Optional[PlanResult]:
         """Coalesce ``d`` through the memo; None -> caller runs legacy.
 
-        The returned planned chain and stats are bit-identical to
-        ``coalesce(d, max_len=max_len, spec_depth=spec_depth,
+        The returned planned chain (host form) and stats are
+        bit-identical to ``coalesce(d, max_len=max_len, spec_depth=spec_depth,
         allow_merge=transform.merge_safe)``; malformed chains (cycles,
         bad links) decline so the legacy walker raises its canonical
         error. A non-identity ``transform`` joins the signature as its
@@ -526,9 +521,6 @@ class TranslationCache:
         with (NO_SPAN if tr is None else
               tr.span("translate.plan", self.track, ring=rec)) as sp:
             canon = canonicalize(d, head)
-            if tr is not None:
-                tr.count("d2h_reads",
-                         CANONICALIZE_READS if canon is not None else 1)
             if canon is None:
                 if rec:
                     sp.ring = False
@@ -549,11 +541,11 @@ class TranslationCache:
                     self._plans.popitem(last=False)
 
             if plan.n_out == 0:
-                planned = DescriptorArray.create(
+                planned = DescriptorArray.from_host(
                     np.zeros(0, np.int64), np.zeros(0, np.int64),
                     np.zeros(0, np.int64))
             else:
-                planned = DescriptorArray.create(
+                planned = DescriptorArray.from_host(
                     plan.rel_src + canon.src_base,
                     plan.rel_dst + canon.dst_base,
                     plan.length, config=plan.config)
@@ -627,8 +619,6 @@ class TranslationCache:
         with (NO_SPAN if tr is None else
               tr.span("drain.pull", self.track, ring=False)):
             ad = np.asarray(d.dst)[np.asarray(d.length) >= 0]
-        if tr is not None:
-            tr.count("d2h_reads", 2)
         if np.unique(ad).size != ad.size:
             return None
         sig = ChainSignature(

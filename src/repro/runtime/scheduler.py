@@ -27,7 +27,6 @@ from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.descriptor import CONFIG_IRQ_ENABLE, DescriptorArray
@@ -86,7 +85,7 @@ def _split_bounds(n: int, piece: int) -> Tuple[Tuple[int, int], ...]:
 
 def _split_chain(d: DescriptorArray, piece: int) -> List[DescriptorArray]:
     """Cut a chain into ring-sized sequentially-chained pieces."""
-    return [DescriptorArray.create(
+    return [DescriptorArray.from_host(
         d.src[lo:hi], d.dst[lo:hi], d.length[lo:hi],
         config=d.config[lo:hi])
         for lo, hi in _split_bounds(d.num_descriptors, piece)]
@@ -255,6 +254,12 @@ class DMARuntime:
         # Sampling key = the first ticket this submission will take; the
         # decision is made once here and reused by every child span.
         tr = self.tracer
+        if not d.on_host:
+            # The chain's one crossing: from here to the drain the runtime
+            # plans, packs and reads it on the host.
+            d = d.to_host()
+            if tr is not None:
+                tr.count("d2h_reads")
         rec = tr is not None and tr.sampled(self._next_ticket)
         first_ticket = self._next_ticket
         name = channel if channel is not None \
@@ -392,7 +397,7 @@ class DMARuntime:
                        channel: Optional[str] = None,
                        on_complete=None) -> Ticket:
         """One IRQ-enabled control descriptor (no data movement)."""
-        d = DescriptorArray.create(
+        d = DescriptorArray.from_host(
             [payload], [0], [0],
             nxt=[-1], config=[int(CONFIG_IRQ_ENABLE)])
         return self.submit(SubmitRequest(
@@ -489,8 +494,6 @@ class DMARuntime:
                   tr.span("drain.pull", ch.track, ring=False)):
                 src_rows = set(np.asarray(b.descs.src).tolist())
                 dst_rows = set(np.asarray(b.descs.dst).tolist())
-            if tr is not None:
-                tr.count("d2h_reads", 2)
             if sub and (src_rows & written or dst_rows & written):
                 self._execute_fused(sub, src_name, dst_name)
                 ran += len(sub)
@@ -504,14 +507,9 @@ class DMARuntime:
 
     def _execute_fused(self, items: List[Tuple[Channel, object]],
                        src_name: str, dst_name: str) -> None:
-        descs = [b.descs for _, b in items]
-        fused = DescriptorArray.create(
-            jnp.concatenate([d.src for d in descs]),
-            jnp.concatenate([d.dst for d in descs]),
-            jnp.concatenate([d.length for d in descs]),
-            nxt=jnp.concatenate([jnp.asarray(d.nxt) for d in descs]),
-            config=jnp.concatenate([d.config for d in descs]),
-        )
+        fused = DescriptorArray.from_host(**{
+            f: np.concatenate([getattr(b.descs, f) for _, b in items])
+            for f in ("src", "dst", "length", "nxt", "config")})
         t0 = monotonic()
         out = None
         tr = self.tracer

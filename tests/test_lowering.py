@@ -134,9 +134,12 @@ def _assert_plan_matches_coalesce(cache, d, max_len, spec_depth=0):
     want_d, want_stats = coalesce(d, max_len=max_len, spec_depth=spec_depth)
     _chains_equal(res.planned, want_d)
     assert res.stats == want_stats
+    # The runtime's own chains stay on the host whatever form came in.
+    assert res.planned.on_host and want_d.on_host
 
 
-def test_plan_is_bit_identical_to_coalesce_on_handcrafted_chains():
+@pytest.mark.parametrize("form", ["device", "host"])
+def test_plan_is_bit_identical_to_coalesce_on_handcrafted_chains(form):
     cache = TranslationCache()
     cases = [
         from_segments([0, 8, 16], [0, 8, 16], [8, 8, 8]),     # merges to 1
@@ -147,19 +150,24 @@ def test_plan_is_bit_identical_to_coalesce_on_handcrafted_chains():
         DescriptorArray.create([0, 8, 16], [0, 8, 16], [8, 8, 8],
                                config=[0, CONFIG_IRQ_ENABLE, 0]),
     ]
+    if form == "host":
+        cases = [d.to_host() for d in cases]
     for d in cases:
         for max_len in (64, 128):
             _assert_plan_matches_coalesce(cache, d, max_len)
     _assert_plan_matches_coalesce(cache, cases[0], 64, spec_depth=4)
 
 
-def test_plan_matches_coalesce_on_permuted_storage_chain():
+@pytest.mark.parametrize("build", [DescriptorArray.create,
+                                   DescriptorArray.from_host],
+                         ids=["device", "host"])
+def test_plan_matches_coalesce_on_permuted_storage_chain(build):
     cache = TranslationCache()
     perm = np.random.default_rng(7).permutation(12)
     nxt = np.full(12, -1, np.int64)
     nxt[perm[:-1]] = perm[1:]
     src = np.arange(12, dtype=np.int64) * 8
-    d = DescriptorArray.create(src, src + 512, np.full(12, 8), nxt=nxt)
+    d = build(src, src + 512, np.full(12, 8), nxt=nxt)
     res = cache.plan(d, max_len=64, head=int(perm[0]))
     want_d, want_stats = coalesce(d, max_len=64, head=int(perm[0]))
     _chains_equal(res.planned, want_d)

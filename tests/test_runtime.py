@@ -8,11 +8,17 @@ import pytest
 
 from repro.core import descriptor as D
 from repro.core.chain import from_segments
-from repro.core.engine import completion_events, execute_chain_host
+from repro.core.engine import (
+    completion_events,
+    execute_blocked_2d,
+    execute_chain_host,
+    execute_serial,
+)
 from repro.core.simulator import simulate, simulate_multichannel, SimConfig
 from repro.runtime import (
     ChannelConfig,
     CompletionQueue,
+    CompletionRecord,
     DMARuntime,
     RingFull,
     RoundRobinArbiter,
@@ -398,6 +404,140 @@ def test_serve_engine_rejects_runtime_without_completion_channel():
     with pytest.raises(ValueError, match="control-tier channel"):
         ServeEngine(params=None, cfg=None,
                     runtime=default_runtime(2, tier="serial", max_len=8))
+
+
+# ---------------------------------------------------------------------------
+# Host-form chains: submit plans, packs and drains without reading back
+# ---------------------------------------------------------------------------
+
+_IRQ = int(D.CONFIG_IRQ_ENABLE)
+
+
+@pytest.fixture
+def d2h(monkeypatch):
+    """Counts conversions of device arrays to host memory: the ``_value``
+    property (``__array__``, ``int``, ``tolist``) and the buffer protocol
+    (``np.asarray`` on the CPU backend)."""
+    arr_t = type(jnp.zeros(1))
+    value, buffer = arr_t._value, arr_t.__buffer__
+    n = [0]
+
+    def counted_value(self):
+        n[0] += 1
+        return value.fget(self)
+
+    def counted_buffer(self, flags):
+        n[0] += 1
+        return buffer(self, flags)
+
+    monkeypatch.setattr(arr_t, "_value", property(counted_value))
+    monkeypatch.setattr(arr_t, "__buffer__", counted_buffer)
+    return n
+
+
+@pytest.mark.parametrize("fields", [
+    dict(src=[0, 8, 16], dst=[32, 40, 48], length=[8, 8, 8]),
+    dict(src=np.arange(4, dtype=np.int64) * 8, dst=[1, 2, 3, 4],
+         length=[8] * 4, nxt=[3, 0, 1, -1], config=[0, 0, _IRQ, 1]),
+    dict(src=np.zeros(0, np.int64), dst=np.zeros(0, np.int64),
+         length=np.zeros(0, np.int64)),
+], ids=["defaults", "linked", "empty"])
+def test_host_form_matches_create_and_round_trips(fields):
+    dev = D.DescriptorArray.create(**fields)
+    host = D.DescriptorArray.from_host(**fields)
+    assert host.on_host and not dev.on_host
+    assert host.to_host() is host
+    for got in (host, dev.to_host()):
+        for f in ("src", "dst", "length", "nxt", "config", "done"):
+            want = np.asarray(getattr(dev, f))
+            assert isinstance(getattr(got, f), np.ndarray)
+            assert getattr(got, f).dtype == want.dtype, f
+            np.testing.assert_array_equal(getattr(got, f), want, err_msg=f)
+    if host.num_descriptors:
+        # The writeback keeps each chain in its own form, with equal fields.
+        done_h, done_d = host.mark_done(0), dev.mark_done(0)
+        assert done_h.on_host and not host.done.any()
+        np.testing.assert_array_equal(D.to_packed(done_h),
+                                      D.to_packed(done_d))
+
+
+def _parent_serial(d, pools):
+    # The parent runtime: coalesce, the planned chain rebuilt on the device,
+    # the serial engine over it.
+    planned, _ = coalesce(d, max_len=8)
+    planned = D.DescriptorArray.create(planned.src, planned.dst,
+                                       planned.length, config=planned.config)
+    out, _ = execute_serial(planned, jnp.asarray(pools["src"]),
+                            jnp.asarray(pools["dst"]), max_len=8)
+    return D.to_packed(planned), out
+
+
+def _parent_rows(d, pools):
+    out, _ = execute_blocked_2d(d, jnp.asarray(pools["src"]),
+                                jnp.asarray(pools["dst"]))
+    return D.to_packed(d), out
+
+
+# Chain fields, pools and the parent's result: a serial chain whose first
+# two descriptors merge and whose merged run splits at max_len 8; row moves
+# with an IRQ on the last; one control descriptor.
+_SERIAL = (dict(src=[0, 8, 16, 40], dst=[64, 72, 80, 120], length=[8] * 3
+                + [5], config=[0, 0, _IRQ, 0]),
+           dict(src=np.arange(256, dtype=np.float32),
+                dst=np.zeros(256, np.float32)), _parent_serial)
+_ROWS = (dict(src=[3, 7, 1, 12], dst=[0, 5, 9, 14], length=[1] * 4,
+              config=[0, 0, 0, _IRQ]),
+         dict(src=np.arange(128, dtype=np.float32).reshape(16, 8),
+              dst=np.zeros((16, 8), np.float32)), _parent_rows)
+_CONTROL = (dict(src=[11], dst=[0], length=[0], nxt=[-1], config=[_IRQ]),
+            {}, lambda d, pools: (D.to_packed(d), None))
+_ROUTES = {
+    "serial_lowered": (dict(tier="serial", max_len=8), True, _SERIAL),
+    "serial_legacy": (dict(tier="serial", max_len=8), False, _SERIAL),
+    "blocked_2d_kernel": (dict(tier="blocked_2d", use_kernel=True), True,
+                          _ROWS),
+    "blocked_2d_fused": (dict(tier="blocked_2d"), True, _ROWS),
+    "control": (dict(tier="control"), True, _CONTROL),
+}
+
+
+@pytest.mark.parametrize("form", ["device", "host"])
+@pytest.mark.parametrize("route", sorted(_ROUTES))
+def test_submit_keeps_chains_on_the_host_and_matches_the_parent(d2h, route,
+                                                                form):
+    kw, translation, (fields, pools, parent) = _ROUTES[route]
+    build = (D.DescriptorArray.create if form == "device"
+             else D.DescriptorArray.from_host)
+    rt = DMARuntime([ChannelConfig("c", ring_capacity=16, **kw)],
+                    translation=translation)
+    for name, arr in pools.items():
+        rt.register_pool(name, jnp.asarray(arr))
+    chain = build(**fields)
+    want_table, want_dst = parent(D.DescriptorArray.create(**fields), pools)
+
+    before = d2h[0]
+    res = rt.submit(SubmitRequest(
+        chain=chain, src_pool="src" if pools else None,
+        dst_pool="dst" if pools else None, channel="c",
+        on_complete=lambda rec: None, run_coalescer=None if pools else False))
+    ring = rt.channels["c"].ring
+    table = ring.table[ring.live_slots()].copy()
+    if not pools:
+        rt.complete(res.tickets[-1])
+    rt.drain_until_idle()
+    rt.drain_all()
+    records = rt.poll()
+    # One device_get of the six fields for a device-built chain, else none.
+    assert d2h[0] - before == (6 if form == "device" else 0)
+
+    np.testing.assert_array_equal(table, want_table)
+    irq = (table["config"] & _IRQ) != 0
+    last = len(table) - 1
+    assert records == [CompletionRecord(t, "c", t, bool(irq[t]))
+                       for t in range(len(table)) if irq[t] or t == last]
+    if want_dst is not None:
+        np.testing.assert_array_equal(np.asarray(rt.pool("dst")),
+                                      np.asarray(want_dst))
 
 
 # ---------------------------------------------------------------------------
